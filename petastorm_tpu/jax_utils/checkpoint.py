@@ -51,19 +51,17 @@ _checkpointer = None
 def _process_index():
     try:
         import jax
-
-        return jax.process_index()
-    except Exception:  # pragma: no cover - jax missing/uninitialized
+    except ImportError:  # pragma: no cover - jax not installed
         return 0
+    return jax.process_index()
 
 
 def _process_count():
     try:
         import jax
-
-        return jax.process_count()
-    except Exception:  # pragma: no cover - jax missing/uninitialized
+    except ImportError:  # pragma: no cover - jax not installed
         return 1
+    return jax.process_count()
 
 
 def _get_checkpointer():

@@ -20,17 +20,16 @@ def default_shard_options(cur_shard=None, shard_count=None):
     """Fill (cur_shard, shard_count) from the JAX runtime when unset.
 
     Single-process (or JAX absent): (None, None) — no sharding, matching the
-    reference's default behavior.
+    reference's default behavior. A backend that fails to initialize raises.
     """
     if cur_shard is not None or shard_count is not None:
         return cur_shard, shard_count
     try:
         import jax
-
-        if jax.process_count() > 1:
-            return jax.process_index(), jax.process_count()
-    except Exception:  # pragma: no cover - jax missing/uninitialized
-        pass
+    except ImportError:  # pragma: no cover - jax not installed
+        return None, None
+    if jax.process_count() > 1:
+        return jax.process_index(), jax.process_count()
     return None, None
 
 
@@ -208,10 +207,9 @@ def agree_max_batches(local_count, reduce="min"):
     local_count = int(local_count)
     try:
         import jax
-
-        if jax.process_count() == 1:
-            return local_count
-    except Exception:  # pragma: no cover - jax missing/uninitialized
+    except ImportError:  # pragma: no cover - jax not installed
+        return local_count
+    if jax.process_count() == 1:
         return local_count
     import numpy as np
 

@@ -1,4 +1,4 @@
-"""shard_map version-compat shims shared by the parallel model families."""
+"""shard_map vma helper shared by the parallel model families."""
 
 from __future__ import annotations
 
@@ -9,12 +9,9 @@ def mark_varying(x, axis_names):
     """Mark ``x`` as varying over ``axis_names`` for shard_map's vma typing
     (constants mixed with per-shard data inside loop carries need this).
     Idempotent — axes ``x`` already varies over are skipped (pcast rejects
-    re-casting). Handles the pcast→pvary API split in ONE place."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return jax.lax.pvary(x, tuple(axis_names))  # pre-pcast jax versions
-    current = getattr(jax.typeof(x), "vma", frozenset())
+    re-casting)."""
+    current = jax.typeof(x).vma
     missing = tuple(a for a in axis_names if a not in current)
     if not missing:
         return x
-    return pcast(x, missing, to="varying")
+    return jax.lax.pcast(x, missing, to="varying")

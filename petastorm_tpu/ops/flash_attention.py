@@ -816,8 +816,17 @@ def _flash_backward(q, k, v, o_padded, lse, g, block_q, block_k, interpret,
 
 
 def _should_interpret():
-    """Mosaic lowering on real TPU; interpreter elsewhere (CPU tests)."""
-    return jax.default_backend() != "tpu"
+    """Mosaic lowering on TPU; the interpreter only on the CPU backend (the
+    tests). Any other backend raises: a chip that failed to come up must not
+    degrade silently into the Pallas interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash_attention has no kernel for backend {backend!r}; pass "
+        "interpret=True explicitly to run the Pallas interpreter there")
 
 
 # Length-aware block_k default, measured on v5e (alternating A/B, fwd+bwd
@@ -850,8 +859,8 @@ def flash_attention(q, k, v, block_q=None, block_k=None, interpret=None,
     :param block_q / block_k: VMEM tile sizes (``None`` = auto: 128, with
         ``block_k`` rising to 512 once ``T_kv`` reaches 4096 — measured
         faster on v5e at long T; see ``_default_blocks``).
-    :param interpret: force the pallas interpreter (None = auto: interpret
-        off-TPU, Mosaic on TPU).
+    :param interpret: force the pallas interpreter (None = auto: Mosaic on
+        TPU, interpreter on CPU, an error on any other backend).
     :param causal: mask key positions after each query's (last-aligned)
         position — decoder-style attention.
     :param bwd_impl: ``"flash"`` (hand-tiled dq + dk/dv Pallas sweeps,

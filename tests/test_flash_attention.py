@@ -556,3 +556,19 @@ def test_long_t_auto_block_matches_reference():
     for name, a, b in zip(("dq", "dk", "dv"), got, ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False),
+                                            ("gpu", None)])
+def test_interpret_auto_is_cpu_only(monkeypatch, backend, expect):
+    """Auto interpret mode is the CPU backend's alone: a backend without a
+    kernel raises instead of degrading silently into the interpreter."""
+    import importlib
+
+    fa_module = importlib.import_module("petastorm_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa_module.jax, "default_backend", lambda: backend)
+    if expect is None:
+        with pytest.raises(RuntimeError, match="no kernel for backend"):
+            fa_module._should_interpret()
+    else:
+        assert fa_module._should_interpret() is expect
