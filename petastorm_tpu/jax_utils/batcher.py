@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from petastorm_tpu.telemetry import tracing
+
 #: Name of the boolean mask column attached when ``last_batch="pad"``.
 PAD_MASK_KEY = "__pad_mask__"
 
@@ -159,6 +161,12 @@ def batch_iterator(reader, batch_size, last_batch="drop", max_batches=None,
         yield batch
 
 
+def _timed_collate(collate, *args):
+    """``collate(*args)`` inside the ``loader.collate`` span."""
+    with tracing.span("loader.collate"):
+        return collate(*args)
+
+
 def _batch_rows(reader, batch_size, shuffle_buffer_size=0, shuffle_seed=None):
     """Row reader → (collated batch dict, is_full) pairs."""
     buf = []
@@ -192,10 +200,10 @@ def _batch_rows(reader, batch_size, shuffle_buffer_size=0, shuffle_seed=None):
     for row in source:
         buf.append(row)
         if len(buf) == batch_size:
-            yield collate(buf), True
+            yield _timed_collate(collate, buf), True
             buf = []
     if buf:
-        yield collate(buf), False
+        yield _timed_collate(collate, buf), False
 
 
 def _rebatch_column_batches(reader, batch_size):
@@ -232,6 +240,6 @@ def _rebatch_column_batches(reader, batch_size):
             pending[name].append(np.asarray(batch_dict[name]))
         pending_rows += rows_in
         while pending_rows >= batch_size:
-            yield emit(batch_size), True
+            yield _timed_collate(emit, batch_size), True
     if pending_rows:
-        yield emit(pending_rows), False
+        yield _timed_collate(emit, pending_rows), False

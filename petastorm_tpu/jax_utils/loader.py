@@ -29,10 +29,8 @@ default), drops them ("drop"), or rejects them ("error").
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import queue
-import sys
 import threading
 import time
 
@@ -46,6 +44,8 @@ from petastorm_tpu.telemetry.metrics import (
     LOADER_DISPATCH_OVERLAP,
     LOADER_ROWS,
     LOADER_STAGE_SECONDS,
+    READER_READ_BYTES,
+    READER_STAGE_SECONDS,
 )
 
 _SENTINEL = object()
@@ -66,6 +66,10 @@ _STAGES = ("decode", "queue_wait", "wait", "raw_stage", "device_decode",
 #: observations happen INSIDE the raw_stage/device_put windows (one per
 #: target device) — summing it too would double-count.
 _DISPATCH_STAGES = ("raw_stage", "device_decode", "device_put")
+
+#: The in-process reader's stages (``petastorm_reader_stage_seconds``),
+#: reported per iteration as ``reader_<stage>_s``.
+_READER_STAGES = ("read", "decode", "transform", "wait")
 
 #: Per-process loader instance ids — the ``loader`` label value, so each
 #: loader's series are separable in a scrape and the legacy per-iteration
@@ -93,20 +97,6 @@ def _release_loader_metrics(loader_id):
     for stage in _STAGES:
         LOADER_STAGE_SECONDS.remove(loader_id, stage)
     _LOADER_ID_POOL.append(loader_id)
-
-
-def _trace_span(name):
-    """``jax.profiler.TraceAnnotation`` when jax is already loaded, else a
-    no-op — the loader's pipeline stages show up in profiler traces
-    (SURVEY.md §5 tracing note) without forcing a jax import on the
-    numpy-only path (``stage_to_device=False``)."""
-    jax = sys.modules.get("jax")
-    # getattr guard: another thread may be mid-way through `import jax`, in
-    # which case sys.modules already holds a partially-initialized module.
-    profiler = getattr(jax, "profiler", None) if jax is not None else None
-    if profiler is None:
-        return contextlib.nullcontext()
-    return profiler.TraceAnnotation(name)
 
 
 def make_jax_dataloader(reader, batch_size,
@@ -404,6 +394,8 @@ class JaxDataLoader:
                                                             stage)
                          for stage in _STAGES}
         self._m_overlap = LOADER_DISPATCH_OVERLAP.labels(self._loader_id)
+        self._m_reader = {stage: READER_STAGE_SECONDS.labels(stage)
+                          for stage in _READER_STAGES}
         import weakref
 
         self._metrics_finalizer = weakref.finalize(
@@ -457,6 +449,9 @@ class JaxDataLoader:
             "h2d_bytes": self._h2d_bytes,
             "stage": {stage: child.sum
                       for stage, child in self._m_stage.items()},
+            "reader": {stage: (child.sum, child.count)
+                       for stage, child in self._m_reader.items()},
+            "reader_bytes": READER_READ_BYTES.value,
         }
 
     @property
@@ -467,7 +462,11 @@ class JaxDataLoader:
         ``producer_queue_wait_s``, ``device_dispatch_s`` with its
         device-stage components ``raw_stage_s``/``device_decode_s``/
         ``shard_put_s``, ``stall_s``, ``consumer_s``), the dispatch
-        ledger's ``dispatch_overlap_pct`` and staged ``h2d_bytes``, and
+        ledger's ``dispatch_overlap_pct`` and staged ``h2d_bytes``, the
+        in-process reader's worker stages (``reader_read_s``,
+        ``reader_decode_s``, ``reader_transform_s``, ``reader_wait_s``,
+        ``reader_row_groups``, ``reader_read_bytes`` — process-wide
+        series, so every thread-pool reader in the process counts), and
         ``wall_s`` / ``input_stall_pct`` — the
         north-star metric — computed **at read time**, so a monitoring
         thread polling mid-epoch sees this epoch's live stall percentage,
@@ -523,7 +522,14 @@ class JaxDataLoader:
             # stall but below the step bound" by naming the consumer-side
             # residual instead of leaving it unattributed.
             "consumer_s": stage["consumer"],
+            "reader_row_groups": int(self._m_reader["read"].count
+                                     - base["reader"]["read"][1]),
+            "reader_read_bytes": int(READER_READ_BYTES.value
+                                     - base["reader_bytes"]),
         }
+        for name, child in self._m_reader.items():
+            out[f"reader_{name}_s"] = max(
+                0.0, child.sum - base["reader"][name][0])
         if self._source_diag is not None:
             out["source"] = dict(self._source_diag)
         return out
@@ -638,15 +644,11 @@ class JaxDataLoader:
             target = (self._host_queue if self._stage_in_producer
                       else self._queue)
             while True:
-                t0 = time.perf_counter()
-                with _trace_span("petastorm_tpu.loader.decode"):
+                with tracing.span("loader.decode",
+                                  hist=self._m_stage["decode"]):
                     batch = next(batches, _SENTINEL)
-                t1 = time.perf_counter()
-                self._m_stage["decode"].observe(t1 - t0)
                 if batch is _SENTINEL:
                     break
-                if tracing.COLLECTOR.enabled:
-                    tracing.COLLECTOR.record_span("loader.decode", t0, t1)
                 t0 = time.perf_counter()
                 while not self._stop.is_set():
                     try:
@@ -893,15 +895,10 @@ class JaxDataLoader:
                     continue
                 if batch is _SENTINEL:
                     break
-                t0 = time.perf_counter()
-                with _trace_span("petastorm_tpu.loader.device_put"):
+                with tracing.span("loader.device_put"):
                     # _stage observes the dispatch-stage histograms itself
                     # (device_put / raw_stage / device_decode).
                     batch = self._stage(batch)
-                t1 = time.perf_counter()
-                if tracing.COLLECTOR.enabled:
-                    tracing.COLLECTOR.record_span("loader.device_put",
-                                                  t0, t1)
                 while not self._stop.is_set():
                     try:
                         self._queue.put(batch, timeout=0.1)
@@ -1057,42 +1054,33 @@ class JaxDataLoader:
             while True:
                 # Keep device_prefetch batches in flight.
                 while not done and len(inflight) < self._device_prefetch:
-                    t0 = time.perf_counter()
-                    with _trace_span("petastorm_tpu.loader.wait"):
+                    with tracing.span("loader.wait",
+                                      hist=self._m_stage["wait"]) as span:
                         # Direct path: pull the prefetched source here
                         # (its reader threads are the producers); an error
                         # raises inline — no sentinel relay needed.
                         host_batch = (next(direct, _SENTINEL)
                                       if direct is not None
                                       else self._queue.get())
-                    t1 = time.perf_counter()
-                    self._m_stage["wait"].observe(t1 - t0)
+                        # Direct-source batches carry the worker-minted
+                        # batch id (the source sets last_bid as it yields,
+                        # on this same thread) — the key that joins loader
+                        # spans to the batch's worker/client lifecycle.
+                        bid = span.bid = (
+                            getattr(self._batch_source, "last_bid", None)
+                            if direct is not None else None)
                     if host_batch is _SENTINEL:
                         done = True
                         if self._producer_error is not None:
                             raise self._producer_error
                         break
-                    # Direct-source batches carry the worker-minted batch
-                    # id (the source sets last_bid as it yields, on this
-                    # same thread) — the key that joins loader spans to
-                    # the batch's worker/client lifecycle in a trace.
-                    bid = (getattr(self._batch_source, "last_bid", None)
-                           if direct is not None else None)
-                    if collector.enabled:
-                        collector.record_span("loader.wait", t0, t1,
-                                              bid=bid)
                     if self._stage_in_producer:
                         inflight.append(host_batch)  # already on device
                     else:
-                        t0 = time.perf_counter()
-                        with _trace_span("petastorm_tpu.loader.device_put"):
+                        with tracing.span("loader.device_put", bid=bid):
                             # _stage observes the dispatch-stage histograms
                             # itself (device_put/raw_stage/device_decode).
                             inflight.append(self._stage(host_batch))
-                        t1 = time.perf_counter()
-                        if collector.enabled:
-                            collector.record_span("loader.device_put",
-                                                  t0, t1, bid=bid)
                     # Release the host copy now that the device owns one:
                     # keeping it across further fill iterations would pin
                     # up to device_prefetch extra host batches.
@@ -1112,18 +1100,17 @@ class JaxDataLoader:
                     rows_in_batch = int(np.asarray(
                         batch[PAD_MASK_KEY]).sum())
                 self._total_rows_yielded += rows_in_batch
-                t_yield = time.perf_counter()
-                yield batch
-                t_back = time.perf_counter()
+                # The training step between yields: not a loader stage, so
+                # no profiler annotation (it would hold the caller's step).
+                with tracing.span("loader.consumer", bid=bid,
+                                  hist=self._m_stage["consumer"],
+                                  annotate=False):
+                    yield batch
                 # Drop the loader's reference to the consumed batch BEFORE
                 # dispatching the next fill: if the consumer's step donated
                 # (or discarded) these buffers, a lingering reference here
                 # would pin one extra batch of HBM per deep-prefetch slot.
                 batch = None
-                self._m_stage["consumer"].observe(t_back - t_yield)
-                if collector.enabled:
-                    collector.record_span("loader.consumer", t_yield,
-                                          t_back, bid=bid)
         finally:
             self._iter_end = time.perf_counter()
             # A batch_source with its own delivery counters (e.g. the data
@@ -1235,8 +1222,8 @@ class JaxDataLoader:
             step = self._stage_step
             self._stage_step += 1
             observe_shard = self._m_stage["shard_put"].observe
-            t0 = time.perf_counter()
-            with _trace_span("petastorm_tpu.loader.raw_stage"):
+            with tracing.span("loader.raw_stage",
+                              hist=self._m_stage["raw_stage"]):
                 if self._sharding is not None:
                     raw_dev = {
                         name: local_data_to_global_array(
@@ -1246,16 +1233,14 @@ class JaxDataLoader:
                 else:
                     device = self._device or jax.local_devices()[0]
                     raw_dev = jax.device_put(raw, device)
-            self._m_stage["raw_stage"].observe(time.perf_counter() - t0)
             raw_bytes = sum(a.nbytes for a in raw.values())
             self._h2d_bytes += raw_bytes
             self._device_stage.h2d_bytes += raw_bytes
             raw = None  # the kernel owns (and may donate) the raw buffers
-            t0 = time.perf_counter()
-            with _trace_span("petastorm_tpu.loader.device_decode"):
+            with tracing.span("loader.device_decode",
+                              hist=self._m_stage["device_decode"]):
                 out.update(self._device_stage.apply(raw_dev, step))
             raw_dev = None  # donated to the kernel — drop ours immediately
-            self._m_stage["device_decode"].observe(time.perf_counter() - t0)
         return out
 
     # -- checkpoint / resume ----------------------------------------------

@@ -22,6 +22,11 @@ from petastorm_tpu.reader_impl.delivery_tracker import (item_key,
                                                         tag_table)
 from petastorm_tpu.schema.transform import transform_schema
 from petastorm_tpu.schema.unischema import Unischema
+from petastorm_tpu.telemetry import tracing
+from petastorm_tpu.telemetry.metrics import (
+    READER_READ_BYTES,
+    READER_STAGE_SECONDS,
+)
 from petastorm_tpu.workers_pool.worker_base import WorkerBase
 
 
@@ -43,38 +48,47 @@ class ArrowReaderWorker(WorkerBase):
         cache_key = (piece.path, piece.row_group, repr(worker_predicate),
                      tuple(sorted(self._read_schema.fields)),
                      shuffle_row_drop_partition, repr(self._transform_spec))
+        key = item_key(piece_index, shuffle_row_drop_partition[0])
         table = self._cache.get(
             cache_key,
             lambda: self._load_table(piece, worker_predicate,
-                                     shuffle_row_drop_partition),
+                                     shuffle_row_drop_partition, key),
         )
         if table is not None and table.num_rows > 0:
             # Tag rides in schema metadata (not a wrapper object) so the
             # Arrow-IPC serializer keeps transporting plain tables.
-            self.publish_func(tag_table(
-                table, item_key(piece_index, shuffle_row_drop_partition[0])))
+            self.publish_func(tag_table(table, key))
 
-    def _load_table(self, piece, worker_predicate, shuffle_row_drop_partition):
+    def _load_table(self, piece, worker_predicate, shuffle_row_drop_partition,
+                    bid=None):
         columns = sorted(self._read_schema.fields)
-        if worker_predicate is not None:
-            predicate_fields = sorted(worker_predicate.get_fields())
-            all_columns = sorted(set(columns) | set(predicate_fields))
-            table = piece.read(self._filesystem, columns=all_columns)
-            frame = table.to_pandas()
-            values = {f: frame[f] for f in predicate_fields}
-            mask = _vectorized_mask(worker_predicate, values, len(frame))
-            frame = frame[mask]
-            frame = frame[[c for c in columns]]
-            table = pa.Table.from_pandas(frame, preserve_index=False)
-        else:
-            table = piece.read(self._filesystem, columns=columns)
-
-        table = self._drop_partition(table, shuffle_row_drop_partition)
+        with tracing.span("reader.read", bid=bid,
+                          hist=READER_STAGE_SECONDS.labels("read")) as span:
+            if worker_predicate is not None:
+                predicate_fields = sorted(worker_predicate.get_fields())
+                all_columns = sorted(set(columns) | set(predicate_fields))
+                table = piece.read(self._filesystem, columns=all_columns)
+                read_bytes = table.nbytes
+                frame = table.to_pandas()
+                values = {f: frame[f] for f in predicate_fields}
+                mask = _vectorized_mask(worker_predicate, values, len(frame))
+                frame = frame[mask]
+                frame = frame[[c for c in columns]]
+                table = pa.Table.from_pandas(frame, preserve_index=False)
+            else:
+                table = piece.read(self._filesystem, columns=columns)
+                read_bytes = table.nbytes
+            table = self._drop_partition(table, shuffle_row_drop_partition)
+            span.args.update(rows=table.num_rows, bytes=read_bytes)
+        READER_READ_BYTES.inc(read_bytes)
 
         if self._transform_spec is not None:
             frame = table.to_pandas()
             if self._transform_spec.func:
-                frame = self._transform_spec.func(frame)
+                with tracing.span(
+                        "reader.transform", bid=bid,
+                        hist=READER_STAGE_SECONDS.labels("transform")):
+                    frame = self._transform_spec.func(frame)
             result_schema = transform_schema(self._read_schema, self._transform_spec)
             missing = [c for c in result_schema.fields if c not in frame.columns]
             if missing:
@@ -120,8 +134,11 @@ class ArrowResultsQueueReader:
 
     def read_next(self, pool, schema, ngram, timeout=None):
         kwargs = {} if timeout is None else {"timeout": timeout}
-        table = pool.get_results(**kwargs)  # raises EmptyResultError at end
-        key = read_table_tag(table)
+        with tracing.span("reader.wait",
+                          hist=READER_STAGE_SECONDS.labels("wait")) as span:
+            # raises EmptyResultError at end
+            table = pool.get_results(**kwargs)
+            key = span.bid = read_table_tag(table)
         self.last_item_key = key
         if self.delivery_tracker is not None and key is not None:
             self.delivery_tracker.record(key, table.num_rows)

@@ -20,7 +20,6 @@ any decode work, and ``TransformSpec.func`` operates on the decoded
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 
 import numpy as np
@@ -30,7 +29,11 @@ from petastorm_tpu import failpoints as _failpoints
 from petastorm_tpu.reader_impl.delivery_tracker import PiecePayload, item_key
 from petastorm_tpu.schema.codecs import DataframeColumnCodec
 from petastorm_tpu.schema.transform import transform_schema
-from petastorm_tpu.telemetry.metrics import COLUMNAR_KERNEL_SECONDS
+from petastorm_tpu.telemetry import tracing
+from petastorm_tpu.telemetry.metrics import (
+    READER_READ_BYTES,
+    READER_STAGE_SECONDS,
+)
 from petastorm_tpu.workers_pool.worker_base import WorkerBase
 
 
@@ -51,32 +54,39 @@ class ColumnarDecodeWorker(WorkerBase):
                      tuple(sorted(self._read_schema.fields)),
                      shuffle_row_drop_partition, repr(self._transform_spec),
                      "columnar")
+        key = item_key(piece_index, shuffle_row_drop_partition[0])
         batch = self._cache.get(
             cache_key,
             lambda: self._load_batch(piece, worker_predicate,
-                                     shuffle_row_drop_partition),
+                                     shuffle_row_drop_partition, key),
         )
         if batch and len(next(iter(batch.values()))) > 0:
-            self.publish_func(PiecePayload(
-                item_key(piece_index, shuffle_row_drop_partition[0]), batch))
+            self.publish_func(PiecePayload(key, batch))
 
-    def _load_batch(self, piece, worker_predicate, shuffle_row_drop_partition):
+    def _load_batch(self, piece, worker_predicate, shuffle_row_drop_partition,
+                    bid=None):
         columns = sorted(self._read_schema.fields)
-        if worker_predicate is not None:
-            predicate_fields = sorted(worker_predicate.get_fields())
-            unknown = [f for f in predicate_fields
-                       if f not in self._schema.fields]
-            if unknown:
-                raise ValueError(f"Predicate fields not in schema: {unknown}")
-            all_columns = sorted(set(columns) | set(predicate_fields))
-            table = piece.read(self._filesystem, columns=all_columns)
-            mask = self._predicate_mask(table, worker_predicate,
-                                        predicate_fields)
-            table = table.filter(pa.array(mask)).select(columns)
-        else:
-            table = piece.read(self._filesystem, columns=columns)
-
-        table = self._drop_partition(table, shuffle_row_drop_partition)
+        with tracing.span("reader.read", bid=bid,
+                          hist=READER_STAGE_SECONDS.labels("read")) as span:
+            if worker_predicate is not None:
+                predicate_fields = sorted(worker_predicate.get_fields())
+                unknown = [f for f in predicate_fields
+                           if f not in self._schema.fields]
+                if unknown:
+                    raise ValueError(
+                        f"Predicate fields not in schema: {unknown}")
+                all_columns = sorted(set(columns) | set(predicate_fields))
+                table = piece.read(self._filesystem, columns=all_columns)
+                read_bytes = table.nbytes
+                mask = self._predicate_mask(table, worker_predicate,
+                                            predicate_fields)
+                table = table.filter(pa.array(mask)).select(columns)
+            else:
+                table = piece.read(self._filesystem, columns=columns)
+                read_bytes = table.nbytes
+            table = self._drop_partition(table, shuffle_row_drop_partition)
+            span.args.update(rows=table.num_rows, bytes=read_bytes)
+        READER_READ_BYTES.inc(read_bytes)
 
         # The columnar decode boundary: the decode.columnar failpoint's
         # "fallback" action forces this batch through the base-class
@@ -85,24 +95,25 @@ class ColumnarDecodeWorker(WorkerBase):
         fp = _failpoints.ACTIVE
         rowwise = fp is not None and fp.fire("decode.columnar") == "fallback"
         batch = OrderedDict()
-        for name in columns:
-            field = self._read_schema.fields[name]
-            cells = _column_cells(table.column(name))
-            if field.codec is not None:
-                if rowwise:
+        with tracing.span("reader.decode", bid=bid,
+                          hist=READER_STAGE_SECONDS.labels("decode")):
+            for name in columns:
+                field = self._read_schema.fields[name]
+                cells = _column_cells(table.column(name))
+                if field.codec is None:
+                    batch[name] = cells
+                elif rowwise:
                     batch[name] = DataframeColumnCodec.decode_column(
                         field.codec, field, cells)
                 else:
-                    t0 = time.perf_counter()
                     batch[name] = field.codec.decode_column(field, cells)
-                    COLUMNAR_KERNEL_SECONDS.observe(
-                        time.perf_counter() - t0)
-            else:
-                batch[name] = cells
 
         if self._transform_spec is not None:
             if self._transform_spec.func:
-                batch = self._transform_spec.func(batch)
+                with tracing.span(
+                        "reader.transform", bid=bid,
+                        hist=READER_STAGE_SECONDS.labels("transform")):
+                    batch = self._transform_spec.func(batch)
             result_schema = transform_schema(self._read_schema,
                                              self._transform_spec)
             missing = [c for c in result_schema.fields if c not in batch]
@@ -172,7 +183,11 @@ class ColumnarResultsQueueReader:
 
     def read_next(self, pool, schema, ngram, timeout=None):
         kwargs = {} if timeout is None else {"timeout": timeout}
-        batch = pool.get_results(**kwargs)  # raises EmptyResultError at end
+        with tracing.span("reader.wait",
+                          hist=READER_STAGE_SECONDS.labels("wait")) as span:
+            # raises EmptyResultError at end
+            batch = pool.get_results(**kwargs)
+            span.bid = getattr(batch, "item_key", None)
         self.last_item_key = None
         if isinstance(batch, PiecePayload):
             self.last_item_key = batch.item_key

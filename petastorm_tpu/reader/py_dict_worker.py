@@ -22,6 +22,11 @@ from petastorm_tpu.reader_impl.delivery_tracker import (
     item_key,
 )
 from petastorm_tpu.schema.transform import transform_schema
+from petastorm_tpu.telemetry import tracing
+from petastorm_tpu.telemetry.metrics import (
+    READER_READ_BYTES,
+    READER_STAGE_SECONDS,
+)
 from petastorm_tpu.utils import decode_row, decode_table
 from petastorm_tpu.workers_pool.worker_base import WorkerBase
 
@@ -43,14 +48,14 @@ class PyDictReaderWorker(WorkerBase):
         piece = self._pieces[piece_index]
         cache_key = self._cache_key(piece, worker_predicate,
                                     shuffle_row_drop_partition)
+        key = item_key(piece_index, shuffle_row_drop_partition[0])
         rows = self._cache.get(
             cache_key,
             lambda: self._load_rows(piece, worker_predicate,
-                                    shuffle_row_drop_partition),
+                                    shuffle_row_drop_partition, key),
         )
         if rows:
-            self.publish_func(PiecePayload(
-                item_key(piece_index, shuffle_row_drop_partition[0]), rows))
+            self.publish_func(PiecePayload(key, rows))
 
     def _cache_key(self, piece, worker_predicate, shuffle_row_drop_partition):
         # Cached rows are POST-transform: the transform repr must be in the
@@ -60,52 +65,63 @@ class PyDictReaderWorker(WorkerBase):
                 tuple(fields), shuffle_row_drop_partition,
                 repr(self._transform_spec))
 
-    def _load_rows(self, piece, worker_predicate, shuffle_row_drop_partition):
-        if worker_predicate is not None:
-            storage = self._read_with_predicate(piece, worker_predicate)
+    def _load_rows(self, piece, worker_predicate, shuffle_row_drop_partition,
+                   bid=None):
+        this_partition, num_partitions = shuffle_row_drop_partition
+        with tracing.span("reader.read", bid=bid,
+                          hist=READER_STAGE_SECONDS.labels("read")) as span:
+            if worker_predicate is not None:
+                storage, read_bytes = self._read_with_predicate(
+                    piece, worker_predicate)
+            else:
+                storage = piece.read(self._filesystem,
+                                     columns=self._needed_columns())
+                read_bytes = storage.nbytes
             if isinstance(storage, list):
-                # Per-row predicate fallback: rows are already python
-                # dicts, decode each.
+                # Per-row predicate fallback: rows are already python dicts.
                 storage = self._drop_partition(storage,
                                                shuffle_row_drop_partition)
-                decoded = [decode_row(row, self._read_schema)
-                           for row in storage]
+                rows = len(storage)
             else:
-                # Vectorized two-phase read: survivors stayed Arrow all
-                # the way — column-wise decode, no to_pylist on scalar
-                # fields.
-                this_partition, num_partitions = shuffle_row_drop_partition
+                # Survivors stayed Arrow all the way — column-wise decode,
+                # no to_pylist on scalar fields.
                 if num_partitions > 1:
                     import numpy as np
 
                     storage = storage.take(
                         np.arange(this_partition, storage.num_rows,
                                   num_partitions))
+                rows = storage.num_rows
+            span.args.update(rows=rows, bytes=read_bytes)
+        READER_READ_BYTES.inc(read_bytes)
+        with tracing.span("reader.decode", bid=bid,
+                          hist=READER_STAGE_SECONDS.labels("decode")):
+            if isinstance(storage, list):
+                decoded = [decode_row(row, self._read_schema)
+                           for row in storage]
+            else:
                 decoded = decode_table(storage, self._read_schema)
-        else:
-            columns = self._needed_columns()
-            table = piece.read(self._filesystem, columns=columns)
-            this_partition, num_partitions = shuffle_row_drop_partition
-            if num_partitions > 1:
-                import numpy as np
-
-                table = table.take(np.arange(this_partition, table.num_rows,
-                                             num_partitions))
-            decoded = decode_table(table, self._read_schema)
 
         if self._ngram is not None:
             windows = self._ngram.form_ngram(decoded, self._read_schema)
             if self._transform_spec and self._transform_spec.func:
-                windows = [
-                    {offset: self._transform_spec.func(dict(ts_row))
-                     for offset, ts_row in window.items()}
-                    for window in windows
-                ]
+                with tracing.span(
+                        "reader.transform", bid=bid,
+                        hist=READER_STAGE_SECONDS.labels("transform")):
+                    windows = [
+                        {offset: self._transform_spec.func(dict(ts_row))
+                         for offset, ts_row in window.items()}
+                        for window in windows
+                    ]
             return windows
 
-        if self._transform_spec:
-            decoded = [self._apply_transform(row) for row in decoded]
-        return decoded
+        if not self._transform_spec:
+            return decoded
+        if not self._transform_spec.func:
+            return [self._apply_transform(row) for row in decoded]
+        with tracing.span("reader.transform", bid=bid,
+                          hist=READER_STAGE_SECONDS.labels("transform")):
+            return [self._apply_transform(row) for row in decoded]
 
     def _needed_columns(self):
         if self._ngram is not None:
@@ -114,6 +130,7 @@ class PyDictReaderWorker(WorkerBase):
 
     def _read_with_predicate(self, piece, predicate):
         """Two-phase read: predicate columns first, the rest only for survivors.
+        Returns ``(survivors, encoded bytes read)``.
 
         The mask is computed **vectorized** when the predicate exposes a
         column-level form (``pa_mask`` — pyarrow compute on the raw table —
@@ -136,6 +153,7 @@ class PyDictReaderWorker(WorkerBase):
             [self._schema.fields[f] for f in predicate_fields]
         )
         predicate_table = piece.read(self._filesystem, columns=predicate_fields)
+        read_bytes = predicate_table.nbytes
         mask = self._vectorized_predicate_mask(predicate, predicate_view,
                                                predicate_table)
         predicate_rows = None
@@ -151,7 +169,7 @@ class PyDictReaderWorker(WorkerBase):
             predicate_rows = [row for row, kept in zip(all_rows, mask)
                               if kept]
         if not mask.any():
-            return []
+            return [], read_bytes
         keep = pa.array(mask)
         # Predicate fields that belong in the output (the rest were read
         # only to compute the mask).
@@ -170,17 +188,19 @@ class PyDictReaderWorker(WorkerBase):
             if other_columns:
                 other_table = piece.read(self._filesystem,
                                          columns=other_columns)
+                read_bytes += other_table.nbytes
                 other_table = other_table.filter(keep)
                 for name in other_columns:
                     data[name] = other_table.column(name)
             filtered = predicate_table.filter(keep)
             for name in kept_fields:
                 data[name] = filtered.column(name)
-            return pa.table(data)
+            return pa.table(data), read_bytes
         # Per-row mask fallback: the predicate rows are already python
         # dicts (the mask needed them) — merge row-wise as before.
         if other_columns:
             other_table = piece.read(self._filesystem, columns=other_columns)
+            read_bytes += other_table.nbytes
             other_rows = other_table.filter(keep).to_pylist()
         else:
             other_rows = [{} for _ in predicate_rows]
@@ -190,7 +210,7 @@ class PyDictReaderWorker(WorkerBase):
             for name in kept_fields:
                 merged[name] = pred_row[name]
             result.append(merged)
-        return result
+        return result, read_bytes
 
     def _vectorized_predicate_mask(self, predicate, predicate_view, table):
         """Column-level mask, or ``None`` to use the per-row path.
@@ -262,7 +282,11 @@ class PyDictResultsQueueReader:
     def read_next(self, pool, schema, ngram, timeout=None):
         kwargs = {} if timeout is None else {"timeout": timeout}
         while not self._buffer:
-            rows = pool.get_results(**kwargs)  # raises EmptyResultError at end
+            with tracing.span("reader.wait",
+                              hist=READER_STAGE_SECONDS.labels("wait")) as span:
+                # raises EmptyResultError at end
+                rows = pool.get_results(**kwargs)
+                span.bid = getattr(rows, "item_key", None)
             if isinstance(rows, FusedPiecePayload):
                 # A fused pool task already collated + serialized the whole
                 # piece: hand the payload through UNSPLIT (the engine

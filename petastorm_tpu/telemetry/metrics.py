@@ -125,11 +125,6 @@ COLUMNAR_BATCHES = REGISTRY.counter(
     "lost). columnar / (columnar + row_fallback) is the COL%% column of "
     "`service status --watch`",
     labels=("worker", "path"))
-COLUMNAR_KERNEL_SECONDS = REGISTRY.histogram(
-    "petastorm_columnar_kernel_seconds",
-    "Per-column vectorized codec decode time inside the columnar reader "
-    "worker (one observation per codec column per row-group batch — the "
-    "decode_column kernels the row_vs_columnar rewrite bets on)")
 
 # -- service: dispatcher (service/dispatcher.py) -----------------------------
 
@@ -668,6 +663,18 @@ FLIGHT_DUMPS = REGISTRY.counter(
 READER_READERS = REGISTRY.counter(
     "petastorm_reader_readers_total",
     "Reader instances constructed in this process")
+READER_STAGE_SECONDS = REGISTRY.histogram(
+    "petastorm_reader_stage_seconds",
+    "Per-row-group time in each stage of the in-process reader, by stage "
+    "(read = the Parquet read with the predicate filter and row-drop "
+    "partition, decode = every codec column of the row group, transform = "
+    "the TransformSpec func, on the worker threads; wait = the consuming "
+    "thread blocked on the pool's results). Count = row groups",
+    labels=("stage",))
+READER_READ_BYTES = REGISTRY.counter(
+    "petastorm_reader_read_bytes_total",
+    "Encoded (Arrow) bytes the in-process reader's workers read from "
+    "Parquet, before decode")
 READER_ROWGROUPS_PLANNED = REGISTRY.gauge(
     "petastorm_reader_rowgroups_planned",
     "Row-group pieces in the most recently constructed reader's plan "

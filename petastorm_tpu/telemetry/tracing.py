@@ -22,12 +22,19 @@ site when off (``record_span`` returns immediately); arming it is
 or :func:`enable` directly. In a loopback run all stages share one process
 and land in one file; multi-process deployments export one file per process
 and merge on the bid (Perfetto overlays multiple files by pid).
+
+Stages that run inside the trainer's process (the local reader's workers,
+the loader) time themselves with :func:`span`: one pair of clock reads
+feeds the collector, an optional histogram, and a
+``jax.profiler.TraceAnnotation`` — so the same stage also lands in a
+profiler trace, on the clock the device's events are stamped with.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -199,6 +206,68 @@ def disable():
 
 def record_span(name, t_start, t_end, bid=None, args=None):
     COLLECTOR.record_span(name, t_start, t_end, bid=bid, args=args)
+
+
+#: Prefix of every profiler event :func:`span` writes.
+PROFILER_PREFIX = "petastorm_tpu."
+
+
+def _annotation(name, bid):
+    """A ``jax.profiler.TraceAnnotation`` when jax is already loaded, else
+    ``None`` — the numpy-only path never imports jax for a span."""
+    jax = sys.modules.get("jax")
+    # getattr guard: another thread may be mid-way through `import jax`, in
+    # which case sys.modules already holds a partially-initialized module.
+    profiler = getattr(jax, "profiler", None) if jax is not None else None
+    if profiler is None:
+        return None
+    if bid is None:
+        return profiler.TraceAnnotation(PROFILER_PREFIX + name)
+    # Keywords land as stats of the event, beside its bare name.
+    return profiler.TraceAnnotation(PROFILER_PREFIX + name, bid=bid)
+
+
+class span:
+    """Time one pipeline stage: ``with span("loader.wait", hist=child):``.
+
+    One pair of ``perf_counter`` reads feeds (a) a profiler annotation
+    ``petastorm_tpu.<name>`` (carrying ``bid``) when jax is loaded and
+    ``annotate`` is true, (b) the collector's span ``<name>`` when it is
+    armed, and (c) ``hist.observe(duration)`` when a histogram child is
+    given. ``bid`` and ``args`` may be set on the object inside the block
+    (values known only once the stage ran); those reach the collector only.
+    A block that raises records nothing but closes its annotation."""
+
+    __slots__ = ("name", "bid", "args", "_hist", "_annotate", "_annotation",
+                 "_t0")
+
+    def __init__(self, name, bid=None, hist=None, annotate=True, **args):
+        self.name = name
+        self.bid = bid
+        self.args = args
+        self._hist = hist
+        self._annotate = annotate
+
+    def __enter__(self):
+        annotation = (_annotation(self.name, self.bid) if self._annotate
+                      else None)
+        if annotation is not None:
+            annotation.__enter__()
+        self._annotation = annotation
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            if self._hist is not None:
+                self._hist.observe(t1 - self._t0)
+            if COLLECTOR.enabled:
+                COLLECTOR.record_span(self.name, self._t0, t1, bid=self.bid,
+                                      args=self.args)
+        return False
 
 
 def export(path):
