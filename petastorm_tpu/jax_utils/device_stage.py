@@ -54,6 +54,55 @@ def _as_channel_array(value, dtype):
     return arr
 
 
+#: Input dtypes whose every value bf16 holds exactly: only these are
+#: cropped by one-hot selection. Any other dtype would be rounded to 8
+#: significant bits, and a NaN or Inf would spread along its row and
+#: column (0·NaN is NaN), so those batches are sliced per image.
+_BF16_EXACT = (np.dtype(np.uint8), np.dtype(np.int8), np.dtype(np.bool_))
+
+
+def batch_in_tiles(layout):
+    """Whether a ``[B, ...]`` array's device ``layout`` (``arr.format.layout``)
+    tiles the batch: B is one of the two most minor dimensions, which a TPU
+    tiles (8, 128). It puts a uint8 batch there when the other dimensions
+    fit its tiles worse, as for ``[128, 375, 500, 3]``. A per-image crop
+    then loops over the batch, touching one lane of 128 or one sublane of
+    8 a trip, and the stage crops by :func:`_select_crop` instead. A batch
+    on a major dimension (always, in a CPU's row-major layouts), or no
+    layout known, keeps the per-image slice, which is then the faster."""
+    return layout is not None and 0 in layout.major_to_minor[-2:]
+
+
+def _select_crop(x, offsets, flips, crop):
+    """Crop ``[B, H, W, C]`` images of a ``_BF16_EXACT`` dtype at
+    per-image ``offsets`` (and mirror those whose ``flips`` bit is set) by
+    two batched one-hot matmuls: rows ``[B, ch, H]``, then columns
+    ``[B, cw, W]`` whose source column runs backwards for a flipped image.
+
+    Every op is the same across the batch, so a batch in the tiles
+    (:func:`batch_in_tiles`) costs no loop. Exact: each output is one
+    product of an 8-bit value (exact in bf16) with 1.0, summed with zeros.
+    Costs 2·B·ch·W·C·(H + cw) FLOPs; on a TPU the casts fuse into the
+    matmuls, and the temporaries are at most one relaid-out copy of the
+    raw batch.
+    """
+    import jax.numpy as jnp
+
+    ch, cw = crop
+    _, h, w, _ = x.shape
+    rows = offsets[:, :1] + jnp.arange(ch)                       # [B, ch]
+    cols = jnp.arange(cw)
+    if flips is not None:
+        cols = jnp.where(flips[:, None], cw - 1 - cols, cols)
+    cols = offsets[:, 1:] + cols                                 # [B, cw]
+    row_sel = (rows[:, :, None] == jnp.arange(h)).astype(jnp.bfloat16)
+    col_sel = (cols[:, :, None] == jnp.arange(w)).astype(jnp.bfloat16)
+    x = jnp.einsum("bih,bhwc->biwc", row_sel, x.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.bfloat16)
+    return jnp.einsum("bjw,biwc->bijc", col_sel, x,
+                      preferred_element_type=jnp.bfloat16)
+
+
 class DeviceStage:
     """Fused on-device decode/augment: uint8 bytes in, model-dtype pixels out.
 
@@ -67,8 +116,12 @@ class DeviceStage:
         reciprocal precomputed once in numpy so the device and the host
         reference multiply by bit-identical constants.
     :param crop: ``None`` or ``(height, width)`` — a per-image random crop
-        (uniform offsets), applied before the cast so the sliced-away
-        pixels are never cast or normalized.
+        (uniform offsets), applied before the normalize so the sliced-away
+        pixels are never normalized. A uint8, int8 or bool batch whose
+        device layout tiles the batch (:func:`batch_in_tiles`, a TPU's
+        layout for most image sizes) is cropped (and flipped) by an exact
+        one-hot selection that reads the whole raw batch as bf16; any other
+        batch is sliced per image.
     :param flip: random horizontal flip per image (p=0.5).
     :param seed: PRNG seed for crop offsets / flip bits.
     :param donate: donate the raw input buffers to the kernel. ``None``
@@ -158,12 +211,14 @@ class DeviceStage:
         key = jax.random.fold_in(jax.random.PRNGKey(self._seed), step)
         return jax.random.fold_in(key, index)
 
-    def _augment(self, x, key, backend):
+    def _augment(self, x, key, backend, select=False):
         """crop → flip → cast → normalize, identical draw structure on both
-        backends; ``backend`` is the jnp module on device, numpy on host."""
+        backends; ``backend`` is the jnp module on device, numpy on host.
+        ``select`` crops an 8-bit batch by one-hot selection (device only)."""
         import jax
 
         jnp = backend
+        offsets = flips = None
         if self._crop is not None:
             if x.ndim != 4:
                 raise ValueError(
@@ -177,37 +232,44 @@ class DeviceStage:
             offsets = jax.random.randint(
                 crop_key, (b, 2), 0,
                 jnp.asarray([h - ch + 1, w - cw + 1]))
-            if backend is np:
+        if self._flip:
+            key, flip_key = jax.random.split(key)
+            flips = jax.random.bernoulli(flip_key, 0.5, (x.shape[0],))
+        if offsets is not None and select and x.dtype in _BF16_EXACT:
+            # One selection does both the crop and the flip.
+            x = _select_crop(x, offsets, flips, self._crop)
+        else:
+            if offsets is not None and backend is np:
                 offsets = np.asarray(offsets)
                 x = np.stack([img[o[0]:o[0] + ch, o[1]:o[1] + cw]
                               for img, o in zip(x, offsets)])
-            else:
+            elif offsets is not None:
                 def crop_one(img, off):
                     return jax.lax.dynamic_slice(
                         img, (off[0], off[1], 0), (ch, cw, img.shape[2]))
 
                 x = jax.vmap(crop_one)(x, offsets)
-        if self._flip:
-            key, flip_key = jax.random.split(key)
-            flips = jax.random.bernoulli(flip_key, 0.5, (x.shape[0],))
-            if backend is np:
-                flips = np.asarray(flips)
-            # Horizontal = the width axis: second-to-last for channel-last
-            # [B, H, W, C] batches, last for channelless [B, H, W].
-            flipped = jnp.flip(x, axis=x.ndim - 2 if x.ndim >= 4
-                               else x.ndim - 1)
-            x = jnp.where(
-                jnp.reshape(flips, (x.shape[0],) + (1,) * (x.ndim - 1)),
-                flipped, x)
+            if flips is not None:
+                if backend is np:
+                    flips = np.asarray(flips)
+                # Horizontal = the width axis: second-to-last for
+                # channel-last [B, H, W, C] batches, last for channelless
+                # [B, H, W].
+                flipped = jnp.flip(x, axis=x.ndim - 2 if x.ndim >= 4
+                                   else x.ndim - 1)
+                x = jnp.where(
+                    jnp.reshape(flips, (x.shape[0],) + (1,) * (x.ndim - 1)),
+                    flipped, x)
         x = x.astype(self._dtype)
         if self._mean is not None:
             x = (x - self._mean) * self._inv_std
         return x
 
-    def _kernel(self, raw, step):
+    def _kernel(self, raw, step, select=frozenset()):
         import jax.numpy as jnp
 
-        return {name: self._augment(raw[name], self._field_key(step, i), jnp)
+        return {name: self._augment(raw[name], self._field_key(step, i), jnp,
+                                    name in select)
                 for i, name in enumerate(sorted(raw))}
 
     def _build_jit(self, input_platform=None):
@@ -222,7 +284,7 @@ class DeviceStage:
             # on a GPU/TPU host).
             platform = input_platform or jax.local_devices()[0].platform
             donate = platform in ("tpu", "gpu")
-        self._jitted = jax.jit(self._kernel,
+        self._jitted = jax.jit(self._kernel, static_argnames=("select",),
                                donate_argnums=(0,) if donate else ())
 
     def apply(self, raw_device, step):
@@ -244,9 +306,13 @@ class DeviceStage:
                 if devs:
                     platform = next(iter(devs)).platform
             self._build_jit(platform)
-        import numpy as _np
-
-        return self._jitted(dict(raw_device), _np.int32(step))
+        # The crop follows each field's device layout; a numpy input has
+        # none yet, and is sliced.
+        select = frozenset(
+            name for name, arr in raw_device.items()
+            if batch_in_tiles(getattr(getattr(arr, "format", None), "layout",
+                                      None)))
+        return self._jitted(dict(raw_device), np.int32(step), select=select)
 
     # -- host parity reference --------------------------------------------
 

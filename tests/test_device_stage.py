@@ -9,6 +9,8 @@ target device, and the H2D ledger must count uint8 bytes, not float32
 pixels. Runs on the conftest 8-virtual-device CPU mesh.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -110,14 +112,95 @@ def test_cast_normalize_matches_plain_numpy_arithmetic():
     np.testing.assert_array_equal(got, want)
 
 
-def test_crop_flip_exact_selections_match_host_reference():
-    stage = DeviceStage(crop=(8, 6), flip=True, seed=5,
-                        normalize=(127.5, 127.5))
-    raw = {"image": _raw_batches()[0]["image"]}
-    got = np.asarray(stage.apply(dict(raw), 2)["image"])
-    want = stage.host_reference(raw, 2)["image"]
-    assert got.shape == (8, 8, 6, 3)
-    np.testing.assert_array_equal(got, want)
+_CROP_SHAPES = {
+    "whole": ((3, 5, 7, 3), (5, 7)),          # the crop is the whole image
+    "1x1": ((2, 9, 9, 3), (1, 1)),
+    "h_ne_w": ((8, 16, 12, 3), (8, 6)),       # H != W, image and crop
+    "imagenet": ((4, 375, 500, 3), (224, 224)),  # ImageNet's commonest size
+}
+_CROP_CASES = [
+    pytest.param(shape, flip, out, "uint8",
+                 id=f"{shape}-{'flip' if flip else 'noflip'}-{out}")
+    for shape in _CROP_SHAPES for flip in (False, True)
+    for out in ("float32", "bfloat16")
+] + [
+    # Input dtypes: bf16 holds int8 and bool exactly; uint16 above 256 and
+    # float32 (with NaN and Inf) it would round or spread.
+    pytest.param("h_ne_w", True, "float32", src,
+                 id=f"h_ne_w-flip-float32-from-{src}")
+    for src in ("int8", "bool", "uint16", "float32")
+]
+
+
+def _crop_input(shape, src):
+    rng = np.random.RandomState(sum(shape))
+    if src == "uint8":
+        img = rng.randint(0, 256, shape, dtype=np.uint8)
+        img[0, 0, 0] = (0, 255, 128)  # both ends of the uint8 range
+    elif src == "int8":
+        img = rng.randint(-128, 128, shape).astype(np.int8)
+        img[0, 0, 0] = (-128, 127, 0)
+    elif src == "bool":
+        img = rng.randint(0, 2, shape).astype(bool)
+    elif src == "uint16":
+        img = rng.randint(0, 1 << 16, shape).astype(np.uint16)
+        img[:, :, :, 0] = 257  # bf16 would read 256
+    else:
+        img = rng.uniform(-1e6, 1e6, shape).astype(np.float32)
+        img[:, ::3, ::2, 0] = np.nan  # in every crop, not in every row
+        img[:, 1::3, ::2, 1] = np.inf
+    return img
+
+
+@pytest.mark.parametrize("path", ["apply", "select"])
+@pytest.mark.parametrize("shape,flip,out,src", _CROP_CASES)
+def test_crop_flip_exact_selections_match_host_reference(shape, flip, out,
+                                                         src, path):
+    """Both device crops, the per-image slice that ``apply`` takes here and
+    the one-hot selection a TPU takes for a batch in its tiles (forced),
+    agree bit for bit with the numpy reference's slice, flipped or not, in
+    either output dtype; a batch that bf16 cannot hold is sliced even when
+    the selection is asked for."""
+    import jax
+    import ml_dtypes
+
+    shape, crop = _CROP_SHAPES[shape]
+    out_dtype = ml_dtypes.bfloat16 if out == "bfloat16" else np.float32
+    # Seed 1 at step 7 draws flipped and unflipped images in every case.
+    stage = DeviceStage(image_fields=("image",), crop=crop, flip=flip,
+                        seed=1, output_dtype=out_dtype,
+                        normalize=((123.675, 116.28, 103.53),
+                                   (58.395, 57.12, 57.375)))
+    img = _crop_input(shape, src)
+    if path == "apply":
+        got = stage.apply({"image": img}, 7)["image"]
+    else:
+        kernel = functools.partial(stage._kernel, select=frozenset({"image"}))
+        got = jax.jit(kernel)({"image": img}, np.int32(7))["image"]
+    got = np.asarray(got)
+    want = stage.host_reference({"image": img}, 7)["image"]
+    assert got.shape == (shape[0],) + crop + (3,)
+    assert got.dtype == want.dtype == out_dtype
+    if src == "float32":
+        # NaN compares equal to NaN here, and stays where it was.
+        assert np.isnan(want).any() and np.isfinite(want).any()
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_array_equal(got.view(np.uint8),
+                                      want.view(np.uint8))
+
+
+def test_crop_path_follows_the_device_layout():
+    """Only a layout with the batch in its two minor dimensions takes the
+    selection: a CPU array's layout is row-major, and a numpy batch has
+    none. The TPU's layouts are checked in test_tpu_compile.py."""
+    import jax
+
+    from petastorm_tpu.jax_utils.device_stage import batch_in_tiles
+
+    staged = jax.device_put(_raw_batches()[0]["image"])
+    assert not batch_in_tiles(staged.format.layout)
+    assert not batch_in_tiles(None)
 
 
 def test_crop_actually_varies_per_image_and_flip_flips():

@@ -52,6 +52,24 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _default_layout(spec):
+    """The layout the TPU gives an array of ``spec`` (per shard), as
+    ``device_put`` lays it out."""
+    return jax.jit(lambda x: x).lower(spec).compile().input_formats[0][0] \
+        .layout
+
+
+def _compile_stage(stage, raw, step):
+    """The stage's program as ``DeviceStage.apply`` picks it for ``raw``'s
+    default layouts."""
+    from petastorm_tpu.jax_utils.device_stage import batch_in_tiles
+
+    select = frozenset(name for name, spec in raw.items()
+                       if batch_in_tiles(_default_layout(spec)))
+    kernel = functools.partial(stage._kernel, select=select)
+    return select, jax.jit(kernel).lower(raw, step).compile()
+
+
 def _compile_flash_fwd_bwd(q, k, v, segment_ids=None):
     from petastorm_tpu.ops.flash_attention import flash_attention
 
@@ -87,11 +105,61 @@ def test_device_stage_imagenet_batch(topo, one_chip):
                         output_dtype=jnp.bfloat16)
     raw = {"image": _spec((128, 375, 500, 3), jnp.uint8, one_chip)}
     step = _spec((), jnp.int32, one_chip)
-    compiled = jax.jit(stage._kernel).lower(raw, step).compile()
+    select, compiled = _compile_stage(stage, raw, step)
+    assert select == {"image"}
     assert compiled.output_shardings["image"].device_set \
         == {topo.devices[0]}
     out = jax.eval_shape(stage._kernel, raw, step)["image"]
     assert (out.shape, out.dtype) == ((128, 224, 224, 3), jnp.bfloat16)
+    # The batch sits on the lanes of this uint8 array's TPU layout: a
+    # per-image slice would lower to a loop of 128 trips, each writing one
+    # lane of a dynamic-update-slice. The one-hot crop is one batched op.
+    hlo = compiled.as_text()
+    assert " while(" not in hlo
+    assert "dynamic-update-slice" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 10 ** 6
+
+
+def test_device_stage_batch_sharded_needs_no_collectives(topo):
+    """The stage on a batch split over ("data", 2) of a 2×2 mesh, as
+    ``batch_sharding`` delivers it: the one-hot crop is batched over the
+    images, so each device crops its own rows and nothing crosses chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from petastorm_tpu.jax_utils import DeviceStage, batch_sharding
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    batch_sh = batch_sharding(mesh)
+    stage = DeviceStage(image_fields=("image",), crop=(224, 224), flip=True,
+                        normalize=((123.675, 116.28, 103.53),
+                                   (58.395, 57.12, 57.375)),
+                        output_dtype=jnp.bfloat16)
+    raw = {"image": _spec((128, 375, 500, 3), jnp.uint8, batch_sh)}
+    step = _spec((), jnp.int32, NamedSharding(mesh, PartitionSpec()))
+    select, compiled = _compile_stage(stage, raw, step)
+    assert select == {"image"}  # each shard's 64 images on the sublanes
+    assert compiled.output_shardings["image"].is_equivalent_to(batch_sh, 4)
+    hlo = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", " while("):
+        assert op not in hlo, op
+
+
+@pytest.mark.parametrize("shape,in_tiles", [
+    ((128, 375, 500, 3), True),     # batch on the lanes
+    ((128, 500, 375, 3), True),
+    ((64, 375, 500, 3), True),      # batch on the sublanes
+    ((128, 512, 500, 3), True),
+    ((128, 480, 640, 3), False),    # batch major: the slice is as fast
+    ((128, 1024, 1024, 3), False),  # batch major: the slice is 2x faster
+])
+def test_crop_path_follows_default_layout(one_chip, shape, in_tiles):
+    """Where the TPU lays a uint8 batch out decides the crop, as measured on
+    a v5e (per-image slice against one-hot selection, these shapes)."""
+    from petastorm_tpu.jax_utils.device_stage import batch_in_tiles
+
+    layout = _default_layout(_spec(shape, jnp.uint8, one_chip))
+    assert batch_in_tiles(layout) == in_tiles, layout
 
 
 def test_classifier_step_fits_one_chip(one_chip):
